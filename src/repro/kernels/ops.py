@@ -1,12 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-* ``flash_attention`` — custom_vjp: Pallas forward (TPU), recompute-based
+* ``flash_attention`` — custom_vjp: Pallas forward, recompute-based
   pure-jnp backward (flash-style: no S x T residuals saved).
 * ``ssd_scan`` — chunk-padded wrapper around the SSD Pallas kernel.
 * ``rmsnorm`` — fused norm wrapper.
 
-``interpret=True`` everywhere in this container (CPU); on real TPU the same
-calls run compiled (set ``repro.kernels.INTERPRET = False``).
+The kernels run compiled on a TPU backend and interpreted on the CPU
+backend (tests); any other backend raises.
 """
 from __future__ import annotations
 
@@ -21,7 +21,15 @@ from .flash_attention import flash_attention_pallas
 from .rmsnorm import rmsnorm_pallas
 from .ssd_scan import ssd_scan_pallas
 
-INTERPRET = True  # CPU container: interpret-mode validation
+
+def _interpret() -> bool:
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise NotImplementedError(
+            f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+            f"the default backend is {backend!r}"
+        )
+    return backend == "cpu"
 
 
 # --------------------------------------------------------------------------
@@ -41,7 +49,7 @@ def flash_attention(
 ) -> jnp.ndarray:
     return flash_attention_pallas(
         q, k, v, q_pos, kv_pos, causal=causal, window=window,
-        interpret=INTERPRET,
+        interpret=_interpret(),
     )
 
 
@@ -88,7 +96,7 @@ def ssd_scan(
         Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0)))
         Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0)))
     y, state = ssd_scan_pallas(
-        x, dt, A, Bm, Cm, chunk=chunk, interpret=INTERPRET
+        x, dt, A, Bm, Cm, chunk=chunk, interpret=_interpret()
     )
     return y[:, :S], state
 
@@ -99,4 +107,4 @@ def ssd_scan(
 
 
 def rmsnorm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6):
-    return rmsnorm_pallas(x, scale, eps=eps, interpret=INTERPRET)
+    return rmsnorm_pallas(x, scale, eps=eps, interpret=_interpret())

@@ -8,25 +8,11 @@ import to obtain placeholder devices.
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only where the installed JAX has it (>= 0.4.38-ish);
-    older releases default every axis to Auto, so omitting is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
-    # legacy mesh API (pre jax.make_mesh)
-    import numpy as np
-
-    devices = np.asarray(jax.devices()[: int(np.prod(shape))]).reshape(shape)
-    return jax.sharding.Mesh(devices, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

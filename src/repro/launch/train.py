@@ -27,6 +27,7 @@ from ..train.data import DataLoader
 from ..train.fault_tolerance import StragglerDetector
 from ..train.optimizer import AdamWConfig
 from ..train.train_step import init_train_state, make_train_step
+from .compile_cache import enable_compile_cache
 
 
 def train_loop(
@@ -76,16 +77,18 @@ def train_loop(
 
     stragglers = StragglerDetector()
     losses = []
+    step_times = []
     for step in range(start_step, steps):
         batch_np = loader.next()
-        t0 = time.time()
+        t0 = time.perf_counter()
         state, metrics = step_fn(
             state, jax.tree.map(jnp.asarray, batch_np)
         )
-        dt = time.time() - t0
+        loss = float(metrics["loss"])  # waits for the step on the device
+        dt = time.perf_counter() - t0
         stragglers.record(host=0, step_time=dt)
-        loss = float(metrics["loss"])
         losses.append(loss)
+        step_times.append(dt)
         if step % log_every == 0 or step == steps - 1:
             print(
                 f"  step {step:5d} loss {loss:8.4f} "
@@ -107,6 +110,8 @@ def train_loop(
         "first_loss": losses[0] if losses else None,
         "last_loss": losses[-1] if losses else None,
         "final_step": steps,
+        # seconds per step, first one including its compile
+        "step_times": step_times,
     }
 
 
@@ -124,6 +129,7 @@ def main() -> None:
     ap.add_argument("--fail-at", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     res = train_loop(
         args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
         reduced=args.reduced, ckpt_dir=args.ckpt_dir,

@@ -107,9 +107,11 @@ def ssd_chunked(
     # ---- intra-chunk (quadratic, masked) --------------------------------
     CB = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)  # [B,nc,Q,Q]
     # decay L[h,i,j] = exp(cum_i - cum_j), lower-triangular inclusive.
+    # Mask before the exp: above the diagonal diff > 0 can overflow, and
+    # an inf there would make the gradient 0 * inf = NaN.
     diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # [B,nc,Q,Q,H]
     mask = jnp.tril(jnp.ones((chunk, chunk), bool))
-    L = jnp.where(mask[None, None, :, :, None], jnp.exp(diff), 0.0)
+    L = jnp.exp(jnp.where(mask[None, None, :, :, None], diff, -jnp.inf))
     W = CB[..., None] * L * dtc[:, :, None, :, :]  # [B,nc,Q(i),Q(j),H]
     y_diag = jnp.einsum("bcijh,bcjhp->bcihp", W, xc)
 

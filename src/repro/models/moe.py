@@ -133,7 +133,6 @@ def apply_moe_shard_map(
     single activation-sized psum.
     """
     from jax.sharding import PartitionSpec as P_
-    from jax.experimental.shard_map import shard_map
 
     E, K, D = cfg.n_experts, cfg.top_k, cfg.d_model
     model_size = mesh.shape["model"]
@@ -186,7 +185,7 @@ def apply_moe_shard_map(
         y = jax.lax.psum(y, "model")
         return y.reshape(B_loc, S, D), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=(
@@ -197,6 +196,6 @@ def apply_moe_shard_map(
             P_("model", None, None),
         ),
         out_specs=(b_spec, P_()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_up"], p["w_gate"], p["w_down"])
     return y, aux

@@ -1,4 +1,5 @@
 """Pallas SSD chunked-scan kernel vs sequential-recurrence oracle."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -61,6 +62,26 @@ def test_model_chunked_path_matches_sequential():
     y2, s2 = ref.ssd_scan_ref(x, dt, A, Bm, Cm)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=2e-4, rtol=2e-4)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=2e-4, rtol=2e-4)
+
+
+def test_model_chunked_path_grads_finite_with_fast_decay():
+    """Full-width decay rates (A down to -16) over 128-long chunks push
+    exp(cum_i - cum_j) past f32 range above the diagonal; the masked
+    entries must not turn the gradient into NaN."""
+    x, dt, A, Bm, Cm = _mk(1, 256, 2, 16, 32)
+    A = jnp.asarray([-16.0, -1.0])
+    dt = dt * 5.0  # up to 0.5: |cum| over a chunk reaches ~1000
+
+    def loss(x, dt, Bm, Cm):
+        y, s = ssd_chunked(x, dt, A, Bm, Cm, chunk=128)
+        return (y**2).sum() + (s**2).sum()
+
+    grads = jax.grad(loss, argnums=(0, 1, 2, 3))(x, dt, Bm, Cm)
+    for g in grads:
+        assert bool(jnp.isfinite(g).all())
+    y1, _ = ssd_chunked(x, dt, A, Bm, Cm, chunk=128)
+    y2, _ = ref.ssd_scan_ref(x, dt, A, Bm, Cm)
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=2e-4, rtol=2e-4)
 
 
 def test_state_enables_continuation():
