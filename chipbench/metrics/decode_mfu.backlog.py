@@ -1,0 +1,16 @@
+"""decode_mfu.backlog: the decode steps' share of the chip's roofline,
+whole steps: the least time each step of the window could take (the larger
+of its FLOPs over peak FLOP/s and its required bytes over peak bandwidth,
+from ``chipbench.work``), summed, over their synced time."""
+from chipbench import work
+
+
+def read(record: dict):
+    if record["kind"] != "serve_closed" or not record.get("decode"):
+        return None
+    pk = record["peaks"]
+    least = sum(
+        work.least_time(*work.decode_step_work(record["sizes"], rows), pk.bf16_flops, pk.hbm_bw)
+        for rows, _t, _s in record["decode"]
+    )
+    return 100.0 * least / sum(s for _r, _t, s in record["decode"])
