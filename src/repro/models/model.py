@@ -5,6 +5,13 @@ scan over super-blocks of ``attn_period`` sub-layers), so full-size configs
 (up to 398 B params) lower and compile quickly.  Per-block activation
 rematerialization (``jax.checkpoint``) bounds training memory.
 
+Each layer's ops carry a ``jax.named_scope`` (``embed``, ``norm``, ``attn``
+or ``mamba``, ``mlp`` or ``moe``, ``head``) in their HLO ``op_name``
+metadata, through remat and the backward pass, so a device trace can be
+split by layer.  Scopes are metadata only: the compiled code is unchanged,
+and JAX's persistent compile cache leaves metadata out of its key, so a
+program loaded from a cache filled before the scopes carries none.
+
 Batch dicts per family (see ``input_specs`` in launch/dryrun.py):
   dense/moe/ssm/hybrid : {"tokens": [B,S] i32, "labels": [B,S] i32}
   vlm   : {"tokens": [B,S_text], "labels": [B,S_text],
@@ -69,14 +76,15 @@ def _apply_sub(
     decode: bool,
 ) -> Tuple[jnp.ndarray, Optional[Params], jnp.ndarray]:
     aux = jnp.zeros((), jnp.float32)
-    y = L.rms_norm(h, sub["ln1"])
-    if mixer == "attn":
-        y, new_cache = L.apply_attention(
-            sub["attn"], cfg, y, q_pos,
-            cache=cache, cache_index=cache_index, self_attend=self_attend,
-        )
-    else:
-        if decode:
+    with jax.named_scope("norm"):
+        y = L.rms_norm(h, sub["ln1"])
+    with jax.named_scope(mixer):
+        if mixer == "attn":
+            y, new_cache = L.apply_attention(
+                sub["attn"], cfg, y, q_pos,
+                cache=cache, cache_index=cache_index, self_attend=self_attend,
+            )
+        elif decode:
             y, new_cache = M.apply_mamba_decode(sub["mamba"], cfg, y, cache)
         else:
             y, new_cache = M.apply_mamba(
@@ -84,11 +92,14 @@ def _apply_sub(
             )
     h = _constrain_sub(h + y)
     if ff != "none":
-        y = L.rms_norm(h, sub["ln2"])
+        with jax.named_scope("norm"):
+            y = L.rms_norm(h, sub["ln2"])
         if ff == "dense":
-            y = L.apply_mlp(sub["mlp"], cfg, y)
+            with jax.named_scope("mlp"):
+                y = L.apply_mlp(sub["mlp"], cfg, y)
         else:
-            y, aux = X.apply_moe(sub["moe"], cfg, y)
+            with jax.named_scope("moe"):
+                y, aux = X.apply_moe(sub["moe"], cfg, y)
         h = _constrain_sub(h + y)
     return h, new_cache, aux
 
@@ -228,15 +239,17 @@ class Model:
         self, params: Params, batch: Dict[str, jnp.ndarray]
     ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         cfg = self.cfg
-        h, n_prefix = self._embed_inputs(params, batch)
+        with jax.named_scope("embed"):
+            h, n_prefix = self._embed_inputs(params, batch)
         S = h.shape[1]
         q_pos = jnp.arange(S, dtype=jnp.int32)
         h, _, aux = self._backbone(params, h, q_pos, remat=True)
-        h = L.rms_norm(h, params["final_norm"])
-        if n_prefix:
-            h = h[:, n_prefix:, :]
-        logits = L.unembed(params["embed"], cfg, h)
-        xent, n_tok = L.cross_entropy(logits, batch["labels"])
+        with jax.named_scope("head"):
+            h = L.rms_norm(h, params["final_norm"])
+            if n_prefix:
+                h = h[:, n_prefix:, :]
+            logits = L.unembed(params["embed"], cfg, h)
+            xent, n_tok = L.cross_entropy(logits, batch["labels"])
         loss = xent + 0.01 * aux
         return loss, {"xent": xent, "aux": aux, "n_tokens": n_tok}
 
@@ -269,7 +282,8 @@ class Model:
     ) -> Tuple[jnp.ndarray, Optional[Params]]:
         """Process the prompt; returns (last-token logits, filled cache)."""
         cfg = self.cfg
-        h, _ = self._embed_inputs(params, batch)
+        with jax.named_scope("embed"):
+            h, _ = self._embed_inputs(params, batch)
         S = h.shape[1]
         q_pos = jnp.arange(S, dtype=jnp.int32)
         h, new_cache, _ = self._backbone(
@@ -278,8 +292,9 @@ class Model:
             cache_index=jnp.zeros((), jnp.int32),
             self_attend=True,
         )
-        h = L.rms_norm(h, params["final_norm"])
-        logits = L.unembed(params["embed"], cfg, h[:, -1:, :])
+        with jax.named_scope("head"):
+            h = L.rms_norm(h, params["final_norm"])
+            logits = L.unembed(params["embed"], cfg, h[:, -1:, :])
         return logits, new_cache
 
     def decode_step(
@@ -294,7 +309,8 @@ class Model:
         per-row ``[B]`` vector when rows sit at different depths (the
         batched serving engine's continuous-refill loop)."""
         cfg = self.cfg
-        h = L.embed_tokens(params["embed"], tokens)
+        with jax.named_scope("embed"):
+            h = L.embed_tokens(params["embed"], tokens)
         pos = pos.astype(jnp.int32)
         q_pos = pos[None] if pos.ndim == 0 else pos[:, None]
         h, new_cache, _ = self._backbone(
@@ -302,8 +318,9 @@ class Model:
             cache=cache, cache_index=pos,
             self_attend=False, decode=True,
         )
-        h = L.rms_norm(h, params["final_norm"])
-        logits = L.unembed(params["embed"], cfg, h)
+        with jax.named_scope("head"):
+            h = L.rms_norm(h, params["final_norm"])
+            logits = L.unembed(params["embed"], cfg, h)
         return logits, new_cache
 
 

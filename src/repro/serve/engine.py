@@ -24,6 +24,12 @@ Correctness properties (tests/test_serve_batched.py):
 
 The per-row position path needs the slot == position invariant, so the
 engine rejects sliding-window (ring-buffer) configs at construction.
+
+Each phase of ``generate`` runs inside a ``repro.serve.*`` host span
+(``jax.profiler.TraceAnnotation``), so that a profiler trace says what the
+host was doing while the device waited: ``refill`` per refilled row, with
+``prefill`` and ``insert_row`` inside it, then per step ``sample``,
+``decode`` (dispatch) and ``fetch`` (the logits' copy to the host).
 """
 from __future__ import annotations
 
@@ -34,6 +40,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..configs.base import ArchConfig
 from ..models.model import Model
@@ -163,47 +170,53 @@ class ServeEngine:
             # the row cache into the batch.
             for b in range(B):
                 if row_req[b] is None and pending:
-                    ri = pending.popleft()
-                    r = requests[ri]
-                    logits, row_cache = self._prefill(
-                        self.params,
-                        {"tokens": jnp.asarray([r.prompt], jnp.int32)},
-                        self.model.init_cache(1, self.max_len, dtype=dt),
-                    )
-                    cache = self._insert_row(cache, row_cache, b)
-                    last[b] = np.asarray(logits)[0, 0]
-                    row_req[b] = ri
-                    row_pos[b] = len(r.prompt)
+                    with TraceAnnotation("repro.serve.refill"):
+                        ri = pending.popleft()
+                        r = requests[ri]
+                        with TraceAnnotation("repro.serve.prefill"):
+                            logits, row_cache = self._prefill(
+                                self.params,
+                                {"tokens": jnp.asarray([r.prompt], jnp.int32)},
+                                self.model.init_cache(1, self.max_len, dtype=dt),
+                            )
+                        with TraceAnnotation("repro.serve.insert_row"):
+                            cache = self._insert_row(cache, row_cache, b)
+                        last[b] = np.asarray(logits)[0, 0]
+                        row_req[b] = ri
+                        row_pos[b] = len(r.prompt)
             live = [b for b in range(B) if row_req[b] is not None]
             if not live:
                 break
 
-            for b in live:
-                ri = row_req[b]
-                r = requests[ri]
-                t = self._sample(last[b], r.temperature)
-                if self.eos_id is not None and t == self.eos_id:
-                    r.done = True  # EOS consumed, not returned
-                    row_req[b] = None
-                    continue
-                r.generated.append(t)
-                tok[b, 0] = t
-                if len(r.generated) >= budgets[ri]:
-                    r.done = True
-                    row_req[b] = None
+            with TraceAnnotation("repro.serve.sample"):
+                for b in live:
+                    ri = row_req[b]
+                    r = requests[ri]
+                    t = self._sample(last[b], r.temperature)
+                    if self.eos_id is not None and t == self.eos_id:
+                        r.done = True  # EOS consumed, not returned
+                        row_req[b] = None
+                        continue
+                    r.generated.append(t)
+                    tok[b, 0] = t
+                    if len(r.generated) >= budgets[ri]:
+                        r.done = True
+                        row_req[b] = None
 
             if all(ri is None for ri in row_req) and not pending:
                 break
             # Retired rows ride along as dummies (their stale token at a
             # clamped position): writes stay confined to their own cache
             # row and are replaced wholesale on refill.
-            logits, cache = self._decode(
-                self.params, cache, jnp.asarray(tok),
-                jnp.asarray(
-                    np.minimum(row_pos, self.max_len - 1), jnp.int32
-                ),
-            )
-            arr = np.asarray(logits)[:, 0, :]
+            with TraceAnnotation("repro.serve.decode"):
+                logits, cache = self._decode(
+                    self.params, cache, jnp.asarray(tok),
+                    jnp.asarray(
+                        np.minimum(row_pos, self.max_len - 1), jnp.int32
+                    ),
+                )
+            with TraceAnnotation("repro.serve.fetch"):
+                arr = np.asarray(logits)[:, 0, :]
             for b in range(B):
                 if row_req[b] is not None:
                     last[b] = arr[b]

@@ -86,9 +86,10 @@ def make_train_step(
         if compress_grads and ef is not None:
             grads, ef = compress_decompress_with_feedback(grads, ef)
 
-        new_params, new_opt, opt_metrics = adamw_update(
-            opt_cfg, state.params, grads, state.opt
-        )
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_metrics = adamw_update(
+                opt_cfg, state.params, grads, state.opt
+            )
         metrics = dict(metrics)
         metrics.update(opt_metrics)
         metrics["loss"] = loss
