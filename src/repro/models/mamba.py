@@ -157,8 +157,9 @@ def apply_mamba(
 ) -> Tuple[jnp.ndarray, Optional[Params]]:
     """Mamba2 block. Training/prefill path (full sequence, chunked scan).
 
-    If ``return_cache``, also returns {"conv": [B,k-1,Ch], "ssm": [B,H,N,P]}
-    for subsequent decode steps.
+    If ``return_cache``, also returns {"conv": [B,k-1,Ch], "ssm": [B,H,P,N]}
+    for subsequent decode steps: ``ssd_chunked``'s final state [B,H,N,P]
+    transposed, the order ``apply_mamba_decode`` keeps it in.
     """
     di, N, H, P = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
     proj = jnp.einsum("bsd,de->bse", x, p["in_proj"])
@@ -198,7 +199,7 @@ def apply_mamba(
         pre = jnp.einsum("bsd,de->bse", x, p["in_proj"])
         _, xbc_pre, _ = _split_proj(cfg, pre)
         conv_cache = xbc_pre[:, -(k - 1) :, :]
-        new_cache = {"conv": conv_cache, "ssm": final_state}
+        new_cache = {"conv": conv_cache, "ssm": jnp.swapaxes(final_state, -1, -2)}
     return out, new_cache
 
 
@@ -208,7 +209,15 @@ def apply_mamba_decode(
     x: jnp.ndarray,  # [B,1,D]
     cache: Params,
 ) -> Tuple[jnp.ndarray, Params]:
-    """Single-token recurrent step (O(1) in sequence length)."""
+    """Single-token recurrent step (O(1) in sequence length).
+
+    ``cache["ssm"]`` is the f32 state [B,H,P,N], stored and updated with
+    N (the state size) minor.  The update is written as broadcast
+    products and ``y`` as a sum over N, not as dot_generals, so the
+    compiler keeps that layout (N=128 fills the lanes unpadded): a step
+    reads each layer's state at most twice and writes it once, with no
+    relayout.
+    """
     di, N, H, P = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
     k = cfg.ssm_conv
     proj = jnp.einsum("bsd,de->bse", x, p["in_proj"])
@@ -226,13 +235,13 @@ def apply_mamba_decode(
     A = -jnp.exp(p["A_log"])
 
     xh = xs.reshape(-1, H, P).astype(jnp.float32)  # [B,H,P]
-    h = cache["ssm"].astype(jnp.float32)  # [B,H,N,P]
+    h = cache["ssm"].astype(jnp.float32)  # [B,H,P,N]
     decay = jnp.exp(dt * A)  # [B,H]
-    delta = jnp.einsum(
-        "bh,bn,bhp->bhnp", dt, Bm[:, 0].astype(jnp.float32), xh
-    )
-    h = decay[:, :, None, None] * h + delta
-    y = jnp.einsum("bn,bhnp->bhp", Cm[:, 0].astype(jnp.float32), h)
+    B1 = Bm[:, 0].astype(jnp.float32)  # [B,N]
+    C1 = Cm[:, 0].astype(jnp.float32)  # [B,N]
+    h = (decay[..., None, None] * h
+         + (dt[..., None, None] * xh[..., :, None]) * B1[:, None, None, :])
+    y = jnp.sum(C1[:, None, None, :] * h, axis=-1)  # [B,H,P]
     y = y + p["D"][None, :, None] * xh
     y = y.reshape(-1, 1, di).astype(x.dtype)
 
@@ -247,8 +256,8 @@ def init_mamba_cache(cfg: ArchConfig, batch: int, dtype) -> Params:
     ch = cfg.d_inner + 2 * cfg.ssm_state
     return {
         "conv": jnp.zeros((batch, cfg.ssm_conv - 1, ch), dtype=dtype),
-        "ssm": jnp.zeros(
-            (batch, cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim),
+        "ssm": jnp.zeros(  # [B,H,P,N]: see apply_mamba_decode
+            (batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
             jnp.float32,
         ),
     }

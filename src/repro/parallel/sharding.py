@@ -134,7 +134,7 @@ def cache_shardings(cfg: ArchConfig, cache_tree: Any, mesh: Mesh):
 
     attn k/v [n, B, W, G, K]: batch over ('pod','data') if divisible else
     W over 'data'; G over 'model' if divisible.
-    mamba ssm [n, B, H, N, P]: batch over ('pod','data') else H on 'model'.
+    mamba ssm [n, B, H, P, N]: batch over ('pod','data') else H on 'model'.
     """
 
     def one(path, leaf):
@@ -148,7 +148,7 @@ def cache_shardings(cfg: ArchConfig, cache_tree: Any, mesh: Mesh):
             return NamedSharding(mesh, P(None, b, w, g, None))
         if name == "pos":  # [n, 1, W]
             return NamedSharding(mesh, P(None, None, None))
-        if name == "ssm":  # [n, B, H, N, P]
+        if name == "ssm":  # [n, B, H, P, N]
             b = batch_axes(mesh, shape[0])
             h = maybe(mesh, shape[1], "model")
             return NamedSharding(mesh, P(None, b, h, None, None))
