@@ -13,6 +13,7 @@ from . import (  # noqa: F401,E402
     llava_next_mistral_7b,
     mamba2_370m,
     jamba_1_5_large_398b,
+    granite_4_0_h_small,
     hubert_xlarge,
 )
 

@@ -7,7 +7,8 @@ plus the four input-shape cells.  ``family`` selects the block structure:
 * moe     — attention + top-k mixture-of-experts MLP
 * vlm     — dense backbone; frontend is a patch-embedding stub
 * ssm     — Mamba2 (SSD) mixer only, no MLP
-* hybrid  — Jamba-style 1:7 attention:mamba interleave, MoE every 2nd layer
+* hybrid  — Mamba2 layers with one attention layer per ``attn_period``
+            (at its middle), MoE on every ``moe_period``-th layer
 * audio   — encoder-only (bidirectional) transformer, frame-embedding stub
 """
 from __future__ import annotations
@@ -37,18 +38,30 @@ class ArchConfig:
     mlp_gated: bool = True  # SwiGLU vs plain GeLU MLP
     causal: bool = True
     tie_embeddings: bool = False
-    rope_theta: float = 1e6
+    rope_theta: Optional[float] = 1e6  # None: no position embedding (NoPE)
+    attn_scale: Optional[float] = None  # score scale; None: head_dim ** -0.5
+    norm_eps: float = 1e-6  # every RMSNorm, the gated one in Mamba2 included
+    # scalar multipliers (Granite): embedding output, each residual branch,
+    # and a divisor of the logits; each is applied only where it is not 1
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0  # the router's outputs: every expert of the layer
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # apply_moe_shard_map only (dropless elsewhere)
+    shared_d_ff: int = 0  # width of a shared expert applied to every token
+    # the experts this layer holds, [expert_offset, expert_offset +
+    # experts_held): one rank's share under expert parallelism; None: all
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     ssm_conv: int = 4
     ssm_chunk: int = 128
-    # hybrid (Jamba): one attention layer per `attn_period` layers, MoE on
+    # hybrid: one attention layer per `attn_period` layers, MoE on
     # every `moe_period`-th layer.
     attn_period: int = 0
     moe_period: int = 0
@@ -67,8 +80,18 @@ class ArchConfig:
             self.n_experts > 0 and self.top_k > 0
         ):
             raise ValueError("MoE family needs n_experts/top_k")
+        if not 0 <= self.expert_offset <= self.expert_offset + self.n_held <= self.n_experts:
+            raise ValueError(
+                f"held experts [{self.expert_offset}, "
+                f"{self.expert_offset + self.n_held}) outside 0..{self.n_experts}"
+            )
 
     # ---- derived sizes -----------------------------------------------------
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights this layer holds."""
+        return self.n_experts if self.experts_held is None else self.experts_held
 
     @property
     def padded_vocab(self) -> int:
@@ -108,8 +131,8 @@ class ArchConfig:
             return (("attn", "moe"),)
         if self.family == "ssm":
             return (("mamba", "none"),)
-        # hybrid: attention in the middle of the super-block, MoE on odd
-        # positions (Jamba's published 1:7 interleave, MoE every 2 layers).
+        # hybrid: attention in the middle of the super-block, MoE on every
+        # moe_period-th position (Granite 4.0-H: period 10, MoE everywhere).
         kinds = []
         for i in range(self.attn_period):
             mixer = "attn" if i == self.attn_period // 2 else "mamba"

@@ -5,6 +5,10 @@ d_ff=24576 vocab=65536, MoE 16e top-2 — Mamba+attn 1:7 interleave, MoE
 Super-block of 8 layers: attention at position 4, Mamba elsewhere
 (1:7); MoE replaces the dense MLP on every 2nd layer (Jamba's published
 e=2 MoE period). Total params ~= 398B, active ~= 94B.
+
+Not a faithful Jamba: the mixer here is the repo's Mamba-2 SSD with
+state 128, while Jamba's published mixer is Mamba-1 (``mamba_d_state``
+16, ``mamba_dt_rank`` 256). So the entry is no benchmark candidate.
 """
 from .base import ArchConfig
 from .registry import register
@@ -30,4 +34,8 @@ def jamba_1_5_large_398b() -> ArchConfig:
         ssm_head_dim=64,
         ssm_expand=2,
         rope_theta=1e6,
+        notes=(
+            "mixer is Mamba-2 SSD with state 128, not Jamba's published "
+            "Mamba-1 (d_state 16, dt_rank 256): not a faithful Jamba"
+        ),
     )

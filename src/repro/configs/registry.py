@@ -42,6 +42,7 @@ def reduced_config(name: str, **overrides) -> ArchConfig:
         vocab_size=256,
         sliding_window=32 if cfg.sliding_window else None,
         n_experts=4 if cfg.n_experts else 0,
+        shared_d_ff=96 if cfg.shared_d_ff else 0,
         top_k=min(cfg.top_k, 2) if cfg.top_k else 0,
         ssm_state=16 if cfg.ssm_state else 0,
         ssm_head_dim=16 if cfg.ssm_state or cfg.family == "hybrid" else 64,

@@ -109,8 +109,6 @@ def run_cell(
         opt_flags.set_flags(sp=True)
     if "mamba_heads" in opts:
         opt_flags.set_flags(mamba_heads=True)
-    if "moe_ep" in opts:
-        opt_flags.set_flags(moe_ep=True)
     if "moe_a2a" in opts:
         opt_flags.set_flags(moe_a2a=True, mesh=mesh)
     if "sp_sub" in opts:

@@ -37,7 +37,7 @@ def dtype_of(cfg: ArchConfig):
 # --------------------------------------------------------------------------
 
 
-def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
+def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float) -> jnp.ndarray:
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     y = xf * jax.lax.rsqrt(var + eps)
@@ -114,8 +114,8 @@ def _attend_dense(
     k: jnp.ndarray,  # [B,T,G,K]
     v: jnp.ndarray,
     bias: jnp.ndarray,  # [Sq,T] or [B,Sq,T]
+    scale: float,
 ) -> jnp.ndarray:
-    scale = q.shape[-1] ** -0.5
     if bias.ndim == 2:
         bias = bias[None]
     scores = jnp.einsum(
@@ -140,8 +140,10 @@ def multi_head_attention(
     kv_pos: jnp.ndarray,  # [T]  absolute positions of the keys (-1 = empty)
     causal: bool,
     window: Optional[int],
+    scale: Optional[float] = None,  # None: head_dim ** -0.5
 ) -> jnp.ndarray:
-    """GQA attention with optional causality/sliding window.
+    """GQA attention with optional causality/sliding window; scores are
+    scaled by ``scale``.
 
     Long query sequences are processed in chunks with lax.scan so the score
     buffer is O(chunk x T) rather than O(S x T) — this is the pure-XLA
@@ -150,10 +152,12 @@ def multi_head_attention(
     B, Sq, H, K = q.shape
     G = k.shape[2]
     qg = q.reshape(B, Sq, G, H // G, K)
+    if scale is None:
+        scale = K ** -0.5
 
     if Sq <= STREAM_THRESHOLD:
         bias = _mask_bias(q_pos, kv_pos, causal, window)
-        out = _attend_dense(qg, k, v, bias)
+        out = _attend_dense(qg, k, v, bias, scale)
         return out.reshape(B, Sq, H, K)
 
     assert q_pos.ndim == 1, "streaming path is prefill-only (shared q_pos)"
@@ -165,7 +169,7 @@ def multi_head_attention(
     def body(_, inp):
         qc, qp = inp
         bias = _mask_bias(qp, kv_pos, causal, window)
-        return None, _attend_dense(qc, k, v, bias)
+        return None, _attend_dense(qc, k, v, bias, scale)
 
     _, out = jax.lax.scan(
         body, None, (jnp.moveaxis(qg_c, 1, 0), qpos_c)
@@ -190,15 +194,19 @@ def apply_attention(
     ``cache`` given + not self_attend  : decode — write the new k/v at
         ``cache_index`` (ring slot for SWA) and attend over the buffer.
     no cache                           : training — plain self-attention.
+
+    RoPE is applied unless ``cfg.rope_theta`` is None (NoPE); scores are
+    scaled by ``cfg.attn_scale`` (default ``head_dim ** -0.5``).
     """
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dgk->bsgk", x, p["wk"])
     v = jnp.einsum("bsd,dgk->bsgk", x, p["wv"])
     if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"])
-        k = rms_norm(k, p["k_norm"])
-    q = rope(q, q_pos, cfg.rope_theta)
-    k = rope(k, q_pos, cfg.rope_theta)
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+    if cfg.rope_theta is not None:
+        q = rope(q, q_pos, cfg.rope_theta)
+        k = rope(k, q_pos, cfg.rope_theta)
 
     window = cfg.sliding_window
     new_cache = None
@@ -252,11 +260,13 @@ def apply_attention(
         new_cache = {"k": ck, "v": cv, "pos": cpos}
 
     if cache is None or self_attend:
-        out = multi_head_attention(q, k, v, q_pos, q_pos, cfg.causal, window)
+        out = multi_head_attention(
+            q, k, v, q_pos, q_pos, cfg.causal, window, cfg.attn_scale
+        )
     else:
         out = multi_head_attention(
             q, new_cache["k"], new_cache["v"], q_pos, new_cache["pos"][0],
-            cfg.causal, window,
+            cfg.causal, window, cfg.attn_scale,
         )
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return out, new_cache
@@ -281,8 +291,9 @@ def init_attn_cache(
 # --------------------------------------------------------------------------
 
 
-def init_mlp(key: jax.Array, cfg: ArchConfig) -> Params:
-    D, F = cfg.d_model, cfg.d_ff
+def init_mlp(key: jax.Array, cfg: ArchConfig, d_ff: Optional[int] = None) -> Params:
+    """An MLP of width ``d_ff`` (default ``cfg.d_ff``)."""
+    D, F = cfg.d_model, d_ff or cfg.d_ff
     dt = dtype_of(cfg)
     k1, k2, k3 = jax.random.split(key, 3)
     p: Params = {
@@ -319,14 +330,21 @@ def init_embedding(key: jax.Array, cfg: ArchConfig) -> Params:
     return p
 
 
-def embed_tokens(p: Params, tokens: jnp.ndarray) -> jnp.ndarray:
-    return jnp.take(p["tokens"], tokens, axis=0)
+def embed_tokens(p: Params, cfg: ArchConfig, tokens: jnp.ndarray) -> jnp.ndarray:
+    h = jnp.take(p["tokens"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        h = h * cfg.embedding_multiplier
+    return h
 
 
 def unembed(p: Params, cfg: ArchConfig, x: jnp.ndarray) -> jnp.ndarray:
     if cfg.tie_embeddings:
-        return jnp.einsum("bsd,vd->bsv", x, p["tokens"])
-    return jnp.einsum("bsd,dv->bsv", x, p["unembed"])
+        logits = jnp.einsum("bsd,vd->bsv", x, p["tokens"])
+    else:
+        logits = jnp.einsum("bsd,dv->bsv", x, p["unembed"])
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def cross_entropy(

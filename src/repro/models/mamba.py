@@ -189,7 +189,7 @@ def apply_mamba(
     # gated RMSNorm then output projection
     from .layers import rms_norm
 
-    y = rms_norm(y * jax.nn.silu(z), p["norm"])
+    y = rms_norm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
     out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
 
     new_cache = None
@@ -247,7 +247,7 @@ def apply_mamba_decode(
 
     from .layers import rms_norm
 
-    y = rms_norm(y * jax.nn.silu(z), p["norm"])
+    y = rms_norm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
     out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
     return out, {"conv": window[:, 1:, :], "ssm": h}
 

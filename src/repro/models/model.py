@@ -6,9 +6,10 @@ scan over super-blocks of ``attn_period`` sub-layers), so full-size configs
 rematerialization (``jax.checkpoint``) bounds training memory.
 
 Each layer's ops carry a ``jax.named_scope`` (``embed``, ``norm``, ``attn``
-or ``mamba``, ``mlp`` or ``moe``, ``head``) in their HLO ``op_name``
-metadata, through remat and the backward pass, so a device trace can be
-split by layer.  Scopes are metadata only: the compiled code is unchanged,
+or ``mamba``, ``mlp`` or ``moe``, ``head``; inside ``moe`` the expert
+layer's ``route``, ``dispatch``, ``experts``, ``combine`` and ``shared``)
+in their HLO ``op_name`` metadata, through remat and the backward pass,
+so a device trace can be split by layer.  Scopes are metadata only: the compiled code is unchanged,
 and JAX's persistent compile cache leaves metadata out of its key, so a
 program loaded from a cache filled before the scopes carries none.
 
@@ -77,7 +78,7 @@ def _apply_sub(
 ) -> Tuple[jnp.ndarray, Optional[Params], jnp.ndarray]:
     aux = jnp.zeros((), jnp.float32)
     with jax.named_scope("norm"):
-        y = L.rms_norm(h, sub["ln1"])
+        y = L.rms_norm(h, sub["ln1"], cfg.norm_eps)
     with jax.named_scope(mixer):
         if mixer == "attn":
             y, new_cache = L.apply_attention(
@@ -90,18 +91,25 @@ def _apply_sub(
             y, new_cache = M.apply_mamba(
                 sub["mamba"], cfg, y, return_cache=cache is not None
             )
-    h = _constrain_sub(h + y)
+    h = _constrain_sub(h + _residual(cfg, y))
     if ff != "none":
         with jax.named_scope("norm"):
-            y = L.rms_norm(h, sub["ln2"])
+            y = L.rms_norm(h, sub["ln2"], cfg.norm_eps)
         if ff == "dense":
             with jax.named_scope("mlp"):
                 y = L.apply_mlp(sub["mlp"], cfg, y)
         else:
             with jax.named_scope("moe"):
                 y, aux = X.apply_moe(sub["moe"], cfg, y)
-        h = _constrain_sub(h + y)
+        h = _constrain_sub(h + _residual(cfg, y))
     return h, new_cache, aux
+
+
+def _residual(cfg: ArchConfig, y: jnp.ndarray) -> jnp.ndarray:
+    """A branch's output as it joins the residual stream."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return y
 
 
 class Model:
@@ -223,7 +231,7 @@ class Model:
                 params["frontend_proj"],
             )
             return h, 0
-        tok = L.embed_tokens(params["embed"], batch["tokens"])
+        tok = L.embed_tokens(params["embed"], cfg, batch["tokens"])
         if cfg.family == "vlm" and "patch_embeds" in batch:
             pe = batch["patch_embeds"].astype(L.dtype_of(cfg))
             proj = params["projector"]
@@ -245,7 +253,7 @@ class Model:
         q_pos = jnp.arange(S, dtype=jnp.int32)
         h, _, aux = self._backbone(params, h, q_pos, remat=True)
         with jax.named_scope("head"):
-            h = L.rms_norm(h, params["final_norm"])
+            h = L.rms_norm(h, params["final_norm"], cfg.norm_eps)
             if n_prefix:
                 h = h[:, n_prefix:, :]
             logits = L.unembed(params["embed"], cfg, h)
@@ -293,7 +301,7 @@ class Model:
             self_attend=True,
         )
         with jax.named_scope("head"):
-            h = L.rms_norm(h, params["final_norm"])
+            h = L.rms_norm(h, params["final_norm"], cfg.norm_eps)
             logits = L.unembed(params["embed"], cfg, h[:, -1:, :])
         return logits, new_cache
 
@@ -310,7 +318,7 @@ class Model:
         batched serving engine's continuous-refill loop)."""
         cfg = self.cfg
         with jax.named_scope("embed"):
-            h = L.embed_tokens(params["embed"], tokens)
+            h = L.embed_tokens(params["embed"], cfg, tokens)
         pos = pos.astype(jnp.int32)
         q_pos = pos[None] if pos.ndim == 0 else pos[:, None]
         h, new_cache, _ = self._backbone(
@@ -319,7 +327,7 @@ class Model:
             self_attend=False, decode=True,
         )
         with jax.named_scope("head"):
-            h = L.rms_norm(h, params["final_norm"])
+            h = L.rms_norm(h, params["final_norm"], cfg.norm_eps)
             logits = L.unembed(params["embed"], cfg, h)
         return logits, new_cache
 

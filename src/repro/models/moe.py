@@ -1,14 +1,22 @@
-"""Mixture-of-Experts FF layer: top-k router + capacity-bounded dispatch.
+"""Mixture-of-Experts FF layer: top-k router + dropless dispatch over the
+experts this layer holds, plus an optional shared expert.
 
-Dispatch is scatter/gather-based (not dense one-hot einsum) so compiled
-FLOPs track *active* parameters: tokens are routed to ``[E, C, D]`` slabs
-(capacity ``C = T * top_k / E * capacity_factor``), experts run as grouped
-einsums, and outputs are combined with the router probabilities.  Tokens
-over capacity are dropped (standard Switch-style), which the auxiliary
-load-balance loss discourages.
+The router keeps its full width (``cfg.n_experts`` outputs) and every token
+picks its top-k of all experts.  The layer holds the weights of experts
+``[cfg.expert_offset, cfg.expert_offset + cfg.n_held)`` (one rank's share
+under expert parallelism; all of them by default) and computes only their
+part of the result: the (token, slot) assignments to held experts are
+sorted by expert, gathered, run through grouped products
+(``jax.lax.ragged_dot`` with the per-expert group sizes), weighted by their
+gates and summed per token.  Assignments to absent experts add nothing,
+and no assignment is ever dropped, so a token's output does not depend on
+its batch-mates.  A shared expert (``cfg.shared_d_ff``), where the config
+has one, is a gated MLP applied to every token and added.
 
-Sharding: the expert axis ``E`` is sharded over the mesh `model` axis
-(expert parallelism); the scatter/gather induce the token all-to-all.
+Each phase carries a named scope inside the caller's ``moe`` scope:
+``route`` (router product, top-k, aux loss), ``dispatch`` (sort and
+gather), ``experts`` (grouped products), ``combine`` (gate-weighted sum)
+and ``shared``.
 """
 from __future__ import annotations
 
@@ -18,25 +26,48 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
+from . import layers as L
 
 Params = Dict[str, Any]
 
 
 def init_moe(key: jax.Array, cfg: ArchConfig) -> Params:
-    D, F, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    D, F, E, Eh = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.n_held
     dt = jnp.dtype(cfg.dtype)
     k1, k2, k3, k4 = jax.random.split(key, 4)
-    return {
+    p: Params = {
         "router": (jax.random.normal(k1, (D, E)) * D**-0.5).astype(jnp.float32),
-        "w_up": (jax.random.normal(k2, (E, D, F)) * D**-0.5).astype(dt),
-        "w_gate": (jax.random.normal(k3, (E, D, F)) * D**-0.5).astype(dt),
-        "w_down": (jax.random.normal(k4, (E, F, D)) * F**-0.5).astype(dt),
+        "w_up": (jax.random.normal(k2, (Eh, D, F)) * D**-0.5).astype(dt),
+        "w_gate": (jax.random.normal(k3, (Eh, D, F)) * D**-0.5).astype(dt),
+        "w_down": (jax.random.normal(k4, (Eh, F, D)) * F**-0.5).astype(dt),
     }
+    if cfg.shared_d_ff:
+        p["shared"] = L.init_mlp(jax.random.fold_in(key, 4), cfg, cfg.shared_d_ff)
+    return p
 
 
 def moe_capacity(cfg: ArchConfig, n_tokens: int) -> int:
+    """Slots per expert of ``apply_moe_shard_map``'s capacity dispatch."""
     cap = int(round(n_tokens * cfg.top_k / cfg.n_experts * cfg.capacity_factor))
     return max(8, ((cap + 7) // 8) * 8)
+
+
+def route(p: Params, cfg: ArchConfig, xt: jnp.ndarray):
+    """Top-k over all experts for tokens ``xt`` [T,D]: (gates [T,K], a
+    softmax over the k largest logits; expert ids [T,K]; Switch aux loss
+    over the full probabilities).  Taking the top-k of the logits rather
+    than of the probabilities keeps the choice exact where the softmax
+    underflows."""
+    T = xt.shape[0]
+    E, K = cfg.n_experts, cfg.top_k
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"])
+    probs = jax.nn.softmax(logits, axis=-1)  # [T,E]
+    top_l, top_i = jax.lax.top_k(logits, K)  # [T,K]
+    top_p = jax.nn.softmax(top_l, axis=-1)
+    # Switch aux loss: E * sum_e (token fraction_e * mean prob_e).
+    frac = jnp.zeros((E,), jnp.float32).at[top_i.reshape(-1)].add(1.0) / (T * K)
+    aux = E * jnp.sum(frac * probs.mean(axis=0))
+    return top_p, top_i, aux
 
 
 def apply_moe(
@@ -51,66 +82,39 @@ def apply_moe(
         )
     B, S, D = x.shape
     T = B * S
-    E, K = cfg.n_experts, cfg.top_k
-    C = moe_capacity(cfg, T)
+    K, Eh = cfg.top_k, cfg.n_held
     xt = x.reshape(T, D)
 
-    logits = jnp.einsum(
-        "td,de->te", xt.astype(jnp.float32), p["router"]
-    )
-    probs = jax.nn.softmax(logits, axis=-1)  # [T,E]
-    top_p, top_i = jax.lax.top_k(probs, K)  # [T,K]
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("route"):
+        top_p, top_i, aux = route(p, cfg, xt)
 
-    # Switch aux loss: E * sum_e (token fraction_e * mean prob_e).
-    frac = jnp.zeros((E,), jnp.float32).at[top_i.reshape(-1)].add(1.0) / (T * K)
-    aux = E * jnp.sum(frac * probs.mean(axis=0))
+    with jax.named_scope("dispatch"):
+        # Held experts' assignments first, grouped by expert; the rest
+        # (group Eh) last, outside every group.
+        local = top_i.reshape(T * K) - cfg.expert_offset
+        held = (local >= 0) & (local < Eh)
+        group = jnp.where(held, local, Eh)
+        order = jnp.argsort(group, stable=True)  # [T*K] flat (token, slot)
+        sizes = jnp.zeros((Eh + 1,), jnp.int32).at[group].add(1)[:Eh]
+        xs = xt[order // K]  # [T*K,D]
 
-    # Position of each (token, slot) within its expert, row-major priority.
-    flat_e = top_i.reshape(T * K)
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)  # [T*K, E]
-    pos = (jnp.cumsum(onehot, axis=0) - 1) * onehot  # [T*K, E]
-    pos_in_e = jnp.sum(pos, axis=-1)  # [T*K]
-    keep = pos_in_e < C
+    with jax.named_scope("experts"):
+        up = jax.lax.ragged_dot(xs, p["w_up"], sizes)
+        gate = jax.lax.ragged_dot(xs, p["w_gate"], sizes)
+        out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, p["w_down"], sizes)
 
-    # Dispatch tokens into [E, C, D] slabs (dropped tokens -> scattered to a
-    # scratch row C which is sliced off).
-    slot = jnp.where(keep, pos_in_e, C)
-    buf = jnp.zeros((E, C + 1, D), dtype=x.dtype)
-    token_idx = jnp.repeat(jnp.arange(T), K)
-    buf = buf.at[flat_e, slot].add(xt[token_idx])
-    buf = buf[:, :C, :]  # [E,C,D]
+    with jax.named_scope("combine"):
+        # Rows past the held groups are left undefined by the grouped
+        # product: select, do not multiply, them away.
+        w = (top_p.reshape(T * K) * held)[order]
+        out = jnp.where(held[order][:, None], out.astype(jnp.float32) * w[:, None], 0.0)
+        inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * K, dtype=order.dtype))
+        y = out[inv].reshape(T, K, D).sum(axis=1)
 
-    from ..parallel import opt_flags
-
-    if opt_flags.get("moe_ep"):
-        # §Perf: pin the dispatch slabs to expert parallelism so the
-        # scatter lowers to an all-to-all instead of gathering tokens.
-        from jax.sharding import PartitionSpec as P_
-
-        buf = jax.lax.with_sharding_constraint(buf, P_("model", None, None))
-
-    # Expert computation (grouped SwiGLU).
-    up = jnp.einsum("ecd,edf->ecf", buf, p["w_up"])
-    gate = jnp.einsum("ecd,edf->ecf", buf, p["w_gate"])
-    h = jax.nn.silu(gate) * up
-    out = jnp.einsum("ecf,efd->ecd", h, p["w_down"])  # [E,C,D]
-    if opt_flags.get("moe_ep"):
-        from jax.sharding import PartitionSpec as P_
-
-        out = jnp.asarray(
-            jax.lax.with_sharding_constraint(out, P_("model", None, None))
-        )
-
-    # Combine: gather each kept (token, slot) expert output, weight by prob.
-    out_pad = jnp.concatenate(
-        [out, jnp.zeros((E, 1, D), out.dtype)], axis=1
-    )  # row C = zeros for dropped tokens
-    gathered = out_pad[flat_e, slot]  # [T*K, D]
-    weights = (top_p.reshape(T * K) * keep).astype(gathered.dtype)
-    y = jnp.zeros((T, D), dtype=gathered.dtype)
-    y = y.at[token_idx].add(gathered * weights[:, None])
-    return y.reshape(B, S, D), aux
+    if "shared" in p:
+        with jax.named_scope("shared"):
+            y = y + L.apply_mlp(p["shared"], cfg, x).reshape(T, D).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(B, S, D), aux
 
 
 # --------------------------------------------------------------------------
@@ -122,7 +126,11 @@ def apply_moe(
 def apply_moe_shard_map(
     p: Params, cfg: ArchConfig, x: jnp.ndarray, mesh, batch_axes
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Expert-parallel MoE via shard_map.
+    """Expert-parallel MoE via shard_map (opt flag ``moe_a2a`` only).
+
+    It keeps the capacity-bounded (Switch-style) dispatch, which drops
+    assignments past ``moe_capacity`` slots an expert, and it covers only
+    configs that hold every expert and have no shared expert.
 
     Tokens stay data-sharded and replicated over `model`; each model rank
     routes every local token, keeps only slots destined to its own
@@ -134,6 +142,10 @@ def apply_moe_shard_map(
     """
     from jax.sharding import PartitionSpec as P_
 
+    if cfg.n_held != cfg.n_experts or cfg.shared_d_ff:
+        raise NotImplementedError(
+            "apply_moe_shard_map needs every expert held and no shared expert"
+        )
     E, K, D = cfg.n_experts, cfg.top_k, cfg.d_model
     model_size = mesh.shape["model"]
     assert E % model_size == 0
@@ -145,15 +157,7 @@ def apply_moe_shard_map(
         T = B_loc * S
         C = moe_capacity(cfg, T)
         xt = xb.reshape(T, D)
-        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), router)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_i = jax.lax.top_k(probs, K)
-        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
-        frac = (
-            jnp.zeros((E,), jnp.float32).at[top_i.reshape(-1)].add(1.0)
-            / (T * K)
-        )
-        aux = E * jnp.sum(frac * probs.mean(axis=0))
+        top_p, top_i, aux = route({"router": router}, cfg, xt)
         aux = jax.lax.pmean(aux, "model")
 
         rank = jax.lax.axis_index("model")

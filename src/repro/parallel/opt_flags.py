@@ -5,7 +5,6 @@ at trace time.  Flags:
 
   sp           — sequence-parallel residual stream (model.py)
   mamba_heads  — shard SSD head dim over `model` inside the mamba mixer
-  moe_ep       — expert-parallel sharding constraints on MoE dispatch
   batch_axes   — mesh axes the batch dim is sharded over (set automatically)
 """
 from __future__ import annotations
@@ -13,7 +12,6 @@ from __future__ import annotations
 _FLAGS = {
     "sp": False,
     "mamba_heads": False,
-    "moe_ep": False,
     "moe_a2a": False,  # shard_map local-dispatch MoE (§Perf iteration 3)
     "sp_sub": False,  # per-sublayer resharding (REFUTED, kept for ablation)
     "batch_axes": None,
@@ -29,7 +27,7 @@ def set_flags(**kw) -> None:
 
 
 def reset() -> None:
-    set_flags(sp=False, mamba_heads=False, moe_ep=False, moe_a2a=False,
+    set_flags(sp=False, mamba_heads=False, moe_a2a=False,
               sp_sub=False, batch_axes=None, mesh=None)
 
 

@@ -83,6 +83,7 @@ def test_full_param_counts_match_spec():
         "qwen3-moe-30b-a3b": 30.5e9,
         "hubert-xlarge": 0.96e9,
         "moonshot-v1-16b-a3b": 28e9,  # 48L as assigned (HF model uses 27L)
+        "granite-4.0-h-small": 32.2e9,
     }
     for arch, want in expected.items():
         cfg = get_config(arch)

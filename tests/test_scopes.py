@@ -67,3 +67,17 @@ def test_serve_programs_carry_scopes(arch):
     prefill = jax.jit(model.prefill).lower(params, {"tokens": i32(1, 8)}, one).compile().as_text()
     for hlo in (decode, prefill):
         assert {mixer, "norm", "embed", "head"} <= _components(hlo)
+
+
+def test_moe_phases_carry_scopes_in_decode():
+    """The expert layer's phases sit inside ``moe`` in the decode step of a
+    hybrid with held experts and a shared expert."""
+    cfg = reduced_config("granite-4.0-h-small", n_experts=8, top_k=3, experts_held=5)
+    model = Model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(2, 16))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    hlo = jax.jit(model.decode_step).lower(params, cache, i32(2, 1), i32(2)).compile().as_text()
+    paths = re.findall(r'op_name="([^"]*)"', hlo)
+    for phase in ("route", "dispatch", "experts", "combine", "shared"):
+        assert any(f"moe/{phase}/" in p for p in paths), phase

@@ -15,13 +15,13 @@ def _full_logits(model, params, toks):
     h, _ = model._embed_inputs(params, {"tokens": toks})
     qp = jnp.arange(toks.shape[1], dtype=jnp.int32)
     h, _, _ = model._backbone(params, h, qp)
-    h = L.rms_norm(h, params["final_norm"])
+    h = L.rms_norm(h, params["final_norm"], cfg.norm_eps)
     return L.unembed(params["embed"], cfg, h)
 
 
 @pytest.mark.parametrize(
     "arch", ["deepseek-7b", "granite-34b", "mamba2-370m",
-             "jamba-1.5-large-398b"]
+             "jamba-1.5-large-398b", "granite-4.0-h-small"]
 )
 def test_prefill_decode_equals_forward(arch):
     cfg = reduced_config(arch, capacity_factor=16.0)
